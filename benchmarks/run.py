@@ -43,6 +43,8 @@ def main() -> None:
             os.environ["XLA_FLAGS"] = (
                 f"{flags} --xla_force_host_platform_device_count={devices}"
                 .strip())
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     figures = [
         ("fig_microbench", "Figs 6-8: FMA throughput + bandwidth"),
         ("fig_throughput", "Fig 10: playouts/sec vs lanes"),

@@ -1,116 +1,23 @@
-"""Cross-version and cross-backend JAX API shims.
+"""Device-mesh helper shared by the sharded search paths.
 
-The repo targets the new-style ``jax.shard_map`` surface (``check_vma`` /
-``axis_names``).  Older JAX releases (<= 0.4.x) only ship
-``jax.experimental.shard_map.shard_map`` whose equivalent knobs are
-``check_rep`` and ``auto`` (the *complement* of ``axis_names``).  Every
-shard_map call in the codebase goes through :func:`shard_map` below so the
-version split lives in exactly one place.
-
-The streaming dispatch pipeline (core/service.py + core/streaming.py)
-additionally needs two capabilities that vary by backend/version:
-
-* **buffer donation** — :func:`donate_jit` applies ``donate_argnums``
-  only where XLA implements input-output aliasing (GPU/TPU); on CPU the
-  donation would be silently unusable and warn per compile, so the shim
-  degrades to a plain ``jax.jit``;
-* **non-blocking readiness** — :func:`array_is_ready` answers "has this
-  array's producing computation finished?" without forcing a sync, via
-  ``jax.Array.is_ready`` where it exists and a conservative ``True``
-  fallback (callers then pay an ordinary blocking fetch, which is always
-  correct).
+The search service shards its slot pool over exactly one mesh axis
+(``SearchService(mesh=)``, ``GoService(mesh=)``, ``Arena(mesh=)``);
+:func:`make_service_mesh` builds that mesh.
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Optional, Sequence, Set
+from typing import Any, Optional, Sequence
 
 import jax
 import numpy as np
-
-# backends whose XLA compiler implements input-output aliasing, making
-# jit buffer donation effective rather than a per-compile warning
-DONATION_BACKENDS = ("gpu", "tpu", "cuda", "rocm")
-
-# New-style shard_map supports partial-auto (``axis_names`` manual subsets).
-# The old experimental API has an ``auto=`` argument, but its XLA lowering
-# path crashes on non-trivial programs (manual-subgroup check failures), so
-# callers needing partial-auto must provide a full-manual fallback.
-HAS_NATIVE_SHARD_MAP = hasattr(jax, "shard_map")
-
-
-def shard_map(f: Callable, mesh: Any, in_specs: Any, out_specs: Any,
-              check_vma: Optional[bool] = None,
-              axis_names: Optional[Set[str]] = None) -> Callable:
-    """``jax.shard_map`` with new-style kwargs on any supported JAX.
-
-    ``axis_names`` — axes the body is *manual* over (new API).  On old JAX
-    this becomes ``auto = mesh.axis_names - axis_names``.  ``check_vma``
-    maps to the old ``check_rep``.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {}
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = {}
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
-
-
-def donate_jit(fn: Callable, donate_argnums: Sequence[int] = (),
-               static_argnums: Sequence[int] = ()) -> Callable:
-    """``jax.jit`` with buffer donation where the backend implements it.
-
-    On :data:`DONATION_BACKENDS` the listed arguments are donated (their
-    buffers alias the outputs — the dispatch pipeline's slot pool reuses
-    its device memory across supersteps instead of allocating per call).
-    On CPU, XLA ignores donation and warns on every compile, so the shim
-    returns an undonated jit — bit-identical results, no warning spam.
-
-    Callers must treat donated arguments as consumed either way: never
-    hold a reference to a donated input across the call (the streaming
-    ring snapshots exist precisely because the result ring is *excluded*
-    from donation, see core/service.py).
-    """
-    if jax.default_backend() in DONATION_BACKENDS:
-        return jax.jit(fn, static_argnums=tuple(static_argnums),
-                       donate_argnums=tuple(donate_argnums))
-    return jax.jit(fn, static_argnums=tuple(static_argnums))
-
-
-def array_is_ready(x: Any) -> bool:
-    """True when ``x``'s producing computation has already finished.
-
-    Non-blocking: used by the dispatch pipeline's ``reconcile(block=
-    False)`` to skip a not-yet-landed superstep without forcing a host
-    sync.  JAX grew ``jax.Array.is_ready`` in the 0.4.x line; where it
-    is missing the shim answers ``True`` — suitable only for callers
-    about to issue the blocking fetch anyway (a caller that must *never*
-    block, like ``SearchService.peek_landed``, checks for the native
-    method itself and skips instead).
-    """
-    is_ready = getattr(x, "is_ready", None)
-    if is_ready is None:
-        return True
-    return bool(is_ready())
 
 
 def make_service_mesh(n_shard: int, axis: str = "shard",
                       devices: Optional[Sequence[Any]] = None):
     """A one-axis mesh over the first ``n_shard`` devices.
 
-    The SearchService shards its slot pool over exactly one mesh axis;
-    this helper builds that mesh portably (``jax.make_mesh`` only grew a
-    ``devices=`` argument after 0.4.x, and always wants every device).
+    ``jax.make_mesh`` always spans every device; the service wants a
+    prefix of them (a one-shard mesh is the bit-identity control).
     """
     devices = list(jax.devices()) if devices is None else list(devices)
     if not 1 <= n_shard <= len(devices):

@@ -13,9 +13,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.config import MCTSConfig
 from repro.core import tree as tree_lib
 from repro.core.mcts import MCTS

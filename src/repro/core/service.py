@@ -70,7 +70,7 @@ RNG contract:
 Sharding (``mesh=``): the pool splits into ``n_shard`` fully independent
 sub-pools — each shard owns ``slots / n_shard`` slots plus its *own*
 pending queues, result ring, colour counter, and parity — and the jitted
-dispatch runs under ``shard_map`` (repro/compat.py) so every shard steps
+dispatch runs under ``jax.shard_map`` so every shard steps
 on its own device with no per-step collective.  A host-side
 :class:`~repro.core.placement.PlacementPolicy` decides which shard admits
 each submission (the paper's KMP_AFFINITY axis applied to requests), and
@@ -89,10 +89,9 @@ from typing import List, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec
 
-from repro.compat import donate_jit, shard_map
 from repro.core.mcts import MCTS, SearchParams
 from repro.core.placement import CLS_GAME, CLS_SERVE, PlacementPolicy
 from repro.go.board import GoEngine, GoState
@@ -242,7 +241,7 @@ class PoolState(NamedTuple):
     The jit boundary splits this into a donatable *work* half (``ring``
     replaced by ``None``) and the ring: supersteps may reuse the work
     buffers in place on backends with donation, while every ring the
-    host snapshotted stays immutable (``compat.donate_jit``).
+    host snapshotted stays immutable (the dispatch donates its work half).
     """
     slots: _Slots
     games: _Queue         # full-game requests (arena + tournament lanes)
@@ -417,14 +416,14 @@ class SearchService:
         self._shard_filter = None
         self._chunk = slots               # flush granularity
         self._init_state = engine.init_state()
-        self._dispatch = donate_jit(self._dispatch_impl,
-                                    donate_argnums=(0,), static_argnums=(2,))
+        self._dispatch = jax.jit(self._dispatch_impl,
+                                 donate_argnums=(0,), static_argnums=(2,))
         self._push_games = jax.jit(self._push_games_impl)
         self._push_serve = jax.jit(self._push_serve_impl)
         if mesh is not None:
-            self._dispatch_mesh = donate_jit(self._dispatch_mesh_impl,
-                                             donate_argnums=(0,),
-                                             static_argnums=(2,))
+            self._dispatch_mesh = jax.jit(self._dispatch_mesh_impl,
+                                          donate_argnums=(0,),
+                                          static_argnums=(2,))
             self._push_games_mesh = jax.jit(functools.partial(
                 self._push_mesh_impl, which="games"))
             self._push_serve_mesh = jax.jit(functools.partial(
@@ -753,7 +752,7 @@ class SearchService:
 
         The jit boundary splits the pool into the donatable *work* half
         (``work.ring is None``) and the result ring: work buffers may be
-        reused in place across calls (``compat.donate_jit``), while every
+        reused in place across calls (``donate_argnums=(0,)``), while every
         ring is a fresh output so host-held :class:`RingView` snapshots
         stay valid however many supersteps run after them.
         """
@@ -1205,18 +1204,12 @@ class SearchService:
         estimated in-flight occupancy rather than the stale polled
         truth.  Returns whether the estimate was refreshed.
 
-        Requires a real ``jax.Array.is_ready``: on JAX builds without it
-        the peek is skipped entirely — the conservative direction *here*
-        (a blocking read every pump would re-serialise the pipeline;
-        ``compat.array_is_ready``'s ``True`` fallback suits callers who
-        were about to block anyway, not this one).  Estimates depend on
-        device timing, so in streaming workloads placement (and
-        therefore game colouring) may vary run to run — the synchronous
-        path stays deterministic (see core/placement.py).
+        Estimates depend on device timing, so in streaming workloads
+        placement (and therefore game colouring) may vary run to run —
+        the synchronous path stays deterministic (see core/placement.py).
         """
         ring = self._pool.ring
-        is_ready = getattr(ring.count, "is_ready", None)
-        if is_ready is None or not is_ready():
+        if not ring.count.is_ready():
             return False
         # outputs of one executable materialise together: count ready
         # means the ticket column is (at worst trivially) ready too

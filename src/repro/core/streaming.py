@@ -33,8 +33,6 @@ from __future__ import annotations
 import collections
 from typing import List, Optional
 
-from repro.compat import array_is_ready
-
 
 class DispatchPipeline:
     """Keeps up to ``depth`` supersteps in flight over one SearchService.
@@ -108,7 +106,7 @@ class DispatchPipeline:
         if not self._window:
             return []
         head = self._window[0]
-        if not block and not array_is_ready(head.ring.count):
+        if not block and not head.ring.count.is_ready():
             return []
         self._window.popleft()
         out = self.service.poll(view=head if self.depth > 1 else None)

@@ -64,8 +64,11 @@ def tie_break_noise(seed, lane, level, a_iota):
     x = x ^ (x >> 15)
     x = x * jnp.uint32(_AVA2)
     x = x ^ (x >> 16)
-    # top 24 bits -> f32 in [0, 1): exact, no rounding surprises
-    return (x >> 8).astype(jnp.float32) * jnp.float32(NOISE_SCALE / (1 << 24))
+    # top 24 bits -> f32 in [0, 1): exact, no rounding surprises.  The
+    # value is below 2**24, so the int32 hop is exact; it is there because
+    # Mosaic has no uint32 -> float32 cast.
+    return ((x >> 8).astype(jnp.int32).astype(jnp.float32)
+            * jnp.float32(NOISE_SCALE / (1 << 24)))
 
 
 def mcts_select_ref(visit, value, vloss, prior, legal, children, expanded,

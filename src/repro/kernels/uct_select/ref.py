@@ -53,27 +53,40 @@ def uct_scores_ref(child_visit, child_value, child_vloss, prior, legal,
     ignored when it is given.
     """
     b = child_visit.shape[0]
-    c = per_row(c_uct, b)
-    vlw = per_row(vl_weight, b)
+    return uct_scores_rows(
+        child_visit, child_value, child_vloss, prior, legal, has_child,
+        parent_n[:, None], player[:, None], per_row(c_uct, b),
+        per_row(vl_weight, b),
+        None if prior_w is None else per_row(prior_w, b), use_puct=use_puct)
+
+
+def uct_scores_rows(child_visit, child_value, child_vloss, prior, legal,
+                    has_child, parent_n, player, c, vlw, w, *,
+                    use_puct: bool):
+    """:func:`uct_scores_ref` with every per-row value as a ``[B, 1]`` column.
+
+    The fused select kernel scores one ``[1, A]`` row at a time and calls
+    this form directly: Mosaic cannot reshape a 1-D vector into a column.
+    ``w`` is the ``prior_w`` column or ``None``.
+    """
     n_eff = jnp.maximum(child_visit + child_vloss, 1.0)
-    q = (player[:, None] * child_value - child_vloss * vlw) / n_eff
-    if prior_w is not None:
-        w = per_row(prior_w, b)
+    q = (player * child_value - child_vloss * vlw) / n_eff
+    if w is not None:
         m = legal.astype(jnp.float32)
         uniform = m / jnp.maximum(m.sum(-1, keepdims=True), 1.0)
-        pn = jnp.maximum(parent_n, 2.0)[:, None]
+        pn = jnp.maximum(parent_n, 2.0)
         u_uct = c * jnp.sqrt(jnp.log(pn) / n_eff)
         s_uct = jnp.where(has_child, q + u_uct, FPU + uniform)
-        root_term = jnp.sqrt(parent_n)[:, None]
+        root_term = jnp.sqrt(parent_n)
         u_puct = c * prior * root_term / (1.0 + child_visit + child_vloss)
         s_puct = jnp.where(has_child, q + u_puct, c * prior * root_term)
         score = (1.0 - w) * s_uct + w * s_puct
     elif use_puct:
-        root_term = jnp.sqrt(parent_n)[:, None]
+        root_term = jnp.sqrt(parent_n)
         u = c * prior * root_term / (1.0 + child_visit + child_vloss)
         score = jnp.where(has_child, q + u, c * prior * root_term)
     else:
-        pn = jnp.maximum(parent_n, 2.0)[:, None]
+        pn = jnp.maximum(parent_n, 2.0)
         u = c * jnp.sqrt(jnp.log(pn) / n_eff)
         score = jnp.where(has_child, q + u, FPU + prior)
     return jnp.where(legal, score, -BIG)

@@ -33,6 +33,7 @@ import numpy as np
 from repro.analysis.hlo import analyze
 from repro.analysis.roofline import (model_flops, roofline_terms,
                                      useful_fraction)
+from repro.compile_cache import use_compile_cache
 from repro.config import (SHAPES, TrainConfig, get_model_config, list_archs,
                           skip_reason)
 from repro.launch.mesh import make_production_mesh
@@ -309,6 +310,7 @@ def main() -> None:
     ap.add_argument("--save-hlo", default=None)
     ap.add_argument("--force", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     from repro.models import optflags
     optflags.set_level(args.opt)

@@ -27,6 +27,7 @@ from __future__ import annotations
 import argparse
 import signal
 
+from repro.compile_cache import use_compile_cache
 from repro.config import MCTSConfig, apply_overrides
 from repro.core.league import League
 from repro.go import GoEngine
@@ -91,6 +92,7 @@ def main() -> None:
                     help="shard the pool over this many devices")
     ap.add_argument("--placement", default="round_robin")
     args = ap.parse_args()
+    use_compile_cache()
 
     base = MCTSConfig(board_size=args.board, komi=args.komi,
                       lanes=args.lanes, max_nodes=args.max_nodes)

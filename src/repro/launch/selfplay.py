@@ -13,6 +13,7 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.compile_cache import use_compile_cache
 from repro.config import MCTSConfig
 from repro.core.selfplay import effective_speedup_point
 from repro.go import GoEngine
@@ -42,6 +43,7 @@ def main() -> None:
                     help="slot refill: SearchService device-side queue "
                          "(default) or the PR 1 host queue")
     args = ap.parse_args()
+    use_compile_cache()
 
     eng = GoEngine(args.board, args.komi)
     cfg = MCTSConfig(board_size=args.board, komi=args.komi,

@@ -11,6 +11,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.config import get_model_config
 from repro.configs.reduced import reduce_config
 from repro.launch.mesh import make_mesh
@@ -32,6 +33,7 @@ def main() -> None:
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_model_config(args.arch)
     if args.reduced:

@@ -19,6 +19,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.go import GoEngine
 from repro.go.board import BLACK
 from repro.serving.go_service import GoService
@@ -71,6 +72,7 @@ def main() -> None:
                     help="supersteps kept in flight by the streaming "
                          "dispatch pipeline (1 = synchronous)")
     args = ap.parse_args()
+    use_compile_cache()
 
     mesh = None
     if args.shards > 1:

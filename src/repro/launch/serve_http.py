@@ -24,6 +24,7 @@ from __future__ import annotations
 import argparse
 import asyncio
 
+from repro.compile_cache import use_compile_cache
 from repro.serving.go_service import DeadlinePolicy, GoService
 from repro.serving.server import GoMoveServer
 
@@ -86,6 +87,7 @@ def main() -> None:
                     help="minimum downgraded playout budget before a "
                          "deadline'd query is shed instead")
     args = ap.parse_args()
+    use_compile_cache()
     try:
         asyncio.run(serve(args))
     except KeyboardInterrupt:

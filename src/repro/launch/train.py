@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.ckpt import AsyncCheckpointer, latest_step, restore_checkpoint
+from repro.compile_cache import use_compile_cache
 from repro.config import TrainConfig, get_model_config
 from repro.configs.reduced import reduce_config
 from repro.data import Prefetcher, SyntheticLM
@@ -50,6 +51,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_model_config(args.arch)
     if args.reduced:

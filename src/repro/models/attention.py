@@ -22,9 +22,9 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.models import sharding
 
 NEG = -1e30

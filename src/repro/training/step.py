@@ -22,10 +22,9 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
-from repro.compat import shard_map
 from repro.config import TrainConfig
 from repro.optim import (clip_by_global_norm, make_optimizer, make_schedule)
 from repro.parallel.compress import (PowerSGDState, compressed_cross_pod_mean,
@@ -111,17 +110,11 @@ def make_train_step(model, tcfg: TrainConfig, total_steps: Optional[int]
                 ce = jax.lax.pmean(ce, "pod")
                 return grads, loss, ce, psgd
 
-            # New JAX: partial-auto (manual over pod only, data/model stay
-            # auto).  Old JAX: its partial-auto lowering miscompiles, so go
-            # fully manual — params/psgd replicated per device, batch
-            # sharded over pod only.  Same numerics; data/model axes do
-            # redundant compute, acceptable at old-JAX test scale.
-            kw = ({"axis_names": {"pod"}} if compat.HAS_NATIVE_SHARD_MAP
-                  else {})
+            # manual over pod only; data/model stay auto
             grads, loss, ce, new_psgd = shard_map(
                 per_pod, mesh=mesh, in_specs=(P(), P("pod"), P()),
                 out_specs=(P(), P(), P(), P()), check_vma=False,
-                **kw)(state.params, batch, state.psgd)
+                axis_names={"pod"})(state.params, batch, state.psgd)
         else:
             grads, loss, ce = accumulate(state.params, batch)
 

@@ -89,7 +89,7 @@ class TestCompressedPodStep:
     def test_powersgd_cross_pod_mean(self):
         run_sub("""
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.parallel.compress import (init_powersgd,
                                      compressed_cross_pod_mean)
@@ -109,7 +109,7 @@ def f(gp, q, e):
 fn = shard_map(f, mesh=mesh,
                in_specs=(P("pod"), P("pod"), P("pod")),
                out_specs=(P("pod"), P("pod"), P("pod")),
-               check_rep=False)
+               check_vma=False)
 qs = jnp.stack([state.q["w"]] * 2)
 es = jnp.stack([state.error["w"]] * 2)
 mean, q2, e2 = fn(g_pods, qs, es)

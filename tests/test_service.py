@@ -171,6 +171,25 @@ class TestMixedLanes:
             svc.submit_serve(mid_state)
 
 
+class TestDonation:
+    def test_dispatch_consumes_its_work_half(self, svc4, mid_state):
+        """The dispatch donates the pool's work half on every backend, the
+        CPU included: the buffers it was handed are gone after the call.
+        A reference held across it would fail here as it would on the
+        chip.  The result ring is not donated and stays readable."""
+        svc4.reset(seed=0, serve_capacity=4)
+        svc4.submit_serve(mid_state, key=np.array([1, 2], np.uint32))
+        svc4.flush()
+        work = svc4._pool._replace(ring=None)
+        ring = svc4._pool.ring
+        svc4.dispatch()
+        leaves = jax.tree.leaves(work)
+        assert leaves and all(x.is_deleted() for x in leaves)
+        assert not any(x.is_deleted() for x in jax.tree.leaves(ring))
+        assert not any(x.is_deleted()
+                       for x in jax.tree.leaves(svc4._pool))
+
+
 class TestSimsKnob:
     def test_full_budget_bit_identical_to_static_loop(self, engine5,
                                                       jit_search):
