@@ -105,7 +105,6 @@ class Arena:
         self._step = jax.jit(self._step_impl)
         self._refill = jax.jit(self._refill_impl)
         self.host_syncs = 0     # host<->device round-trips of the last run
-        self.host_blocked_s = 0.0   # device-wait time of the last run
 
     @property
     def service(self) -> SearchService:
@@ -227,7 +226,6 @@ class Arena:
             for i in range(games)]
         recs = {r.ticket: r for r in svc.drain()}
         self.host_syncs = svc.host_syncs
-        self.host_blocked_s = svc.host_blocked_s
         return [GameResult(winner=recs[t].winner, moves=recs[t].moves,
                            tree_nodes=recs[t].tree_nodes,
                            a_is_black=recs[t].a_is_black) for t in tickets]
@@ -241,7 +239,6 @@ class Arena:
         G, h = self.slots, self.slots // 2
         host_rng = np.random.default_rng(seed)
         self.host_syncs = 0
-        self.host_blocked_s = 0.0   # per-step syncs; not separately timed
 
         def draw_key(i: int) -> np.ndarray:
             if game_keys is not None:

@@ -242,9 +242,9 @@ class MCTS:
             & t.expanded[node]
 
         def do_expand(t):
-            t2, idx = tree_lib.allocate(self.engine, t, node, act,
-                                        self._expand_prior_fn)
-            return t2, idx
+            with jax.named_scope("mcts_expand"):
+                return tree_lib.allocate(self.engine, t, node, act,
+                                         self._expand_prior_fn)
 
         t, new_idx = jax.lax.cond(
             can_expand, do_expand, lambda t: (t, node), t)
@@ -285,28 +285,31 @@ class MCTS:
             t, path, leaf = self._select_lane(t, key, params)
             return t, (path, leaf)
 
-        t, (paths, leaves) = jax.lax.scan(lane, t, keys[:L])
+        with jax.named_scope("mcts_select"):
+            t, (paths, leaves) = jax.lax.scan(lane, t, keys[:L])
 
         # batched playouts: [L, P]
         pkeys = jax.random.split(keys[L], L * P).reshape(L, P, 2)
         leaf_states = jax.tree.map(lambda x: x[leaves], t.states)
         komi = None if params is None else params.komi
-        if self.value_fn is not None:
-            vals = jax.vmap(self.value_fn)(leaf_states)          # [L]
-            vals = jnp.repeat(vals[:, None], P, axis=1)
-        elif komi is None:
-            vals = jax.vmap(
-                lambda st, ks: jax.vmap(
-                    lambda k: self.engine.playout_value(st, k))(ks)
-            )(leaf_states, pkeys)                                 # [L, P]
-        else:
-            # traced per-search komi (a scalar here: search_batch's vmap
-            # peeled the game axis); broadcasts over every lane/playout
-            vals = jax.vmap(
-                lambda st, ks: jax.vmap(
-                    lambda k: self.engine.playout_value(st, k, komi))(ks)
-            )(leaf_states, pkeys)                                 # [L, P]
-        val_sum = vals.sum(axis=1)                                # black persp.
+        with jax.named_scope("mcts_playout"):
+            if self.value_fn is not None:
+                vals = jax.vmap(self.value_fn)(leaf_states)      # [L]
+                vals = jnp.repeat(vals[:, None], P, axis=1)
+            elif komi is None:
+                vals = jax.vmap(
+                    lambda st, ks: jax.vmap(
+                        lambda k: self.engine.playout_value(st, k))(ks)
+                )(leaf_states, pkeys)                             # [L, P]
+            else:
+                # traced per-search komi (a scalar here: search_batch's
+                # vmap peeled the game axis); broadcasts over every
+                # lane/playout
+                vals = jax.vmap(
+                    lambda st, ks: jax.vmap(
+                        lambda k: self.engine.playout_value(st, k, komi))(ks)
+                )(leaf_states, pkeys)                             # [L, P]
+            val_sum = vals.sum(axis=1)                        # black persp.
 
         prior = t.prior
         if self.evaluator is not None:
@@ -323,18 +326,18 @@ class MCTS:
             prior = prior.at[leaves].set(net_prior)
 
         # exact scatter-add backup over all lanes at once
-        flat = paths.reshape(-1)
-        ok = flat != UNVISITED
-        safe = jnp.maximum(flat, 0)
-        w = jnp.where(ok, 1.0, 0.0)
-        vrep = jnp.repeat(val_sum, self.max_depth)
-        t = t._replace(
-            visit=t.visit.at[safe].add(w * P),
-            value=t.value.at[safe].add(jnp.where(ok, vrep, 0.0)),
-            vloss=jnp.zeros_like(t.vloss),   # FUEGO: remove at backup
-            prior=prior,
-        )
-        return t
+        with jax.named_scope("mcts_backup"):
+            flat = paths.reshape(-1)
+            ok = flat != UNVISITED
+            safe = jnp.maximum(flat, 0)
+            w = jnp.where(ok, 1.0, 0.0)
+            vrep = jnp.repeat(val_sum, self.max_depth)
+            return t._replace(
+                visit=t.visit.at[safe].add(w * P),
+                value=t.value.at[safe].add(jnp.where(ok, vrep, 0.0)),
+                vloss=jnp.zeros_like(t.vloss),   # FUEGO: remove at backup
+                prior=prior,
+            )
 
     # ----------------------------------------------------------------- search
 
@@ -540,36 +543,39 @@ class MCTS:
         pkeys = pkeys.reshape(g, lanes, p, 2)
 
         player = t.states.to_play.astype(jnp.float32)            # [G, N]
-        paths, depth, leaf, act, can_exp, vloss = mcts_select(
-            t.visit, t.value, t.vloss, t.prior, t.legal, t.children,
-            t.expanded, t.terminal, player, seeds,
-            c_uct=c, vl_weight=vlw, prior_w=pw,
-            lanes=lanes, max_depth=self.max_depth,
-            expand_threshold=int(self.cfg.expand_threshold),
-            use_puct=self.use_puct)
+        with jax.named_scope("mcts_select"):
+            paths, depth, leaf, act, can_exp, vloss = mcts_select(
+                t.visit, t.value, t.vloss, t.prior, t.legal, t.children,
+                t.expanded, t.terminal, player, seeds,
+                c_uct=c, vl_weight=vlw, prior_w=pw,
+                lanes=lanes, max_depth=self.max_depth,
+                expand_threshold=int(self.cfg.expand_threshold),
+                use_puct=self.use_puct)
         t = t._replace(vloss=vloss)
-        t, paths, leaves = self._expand_batch(
-            t, paths, depth, leaf, act, can_exp)
+        with jax.named_scope("mcts_expand"):
+            t, paths, leaves = self._expand_batch(
+                t, paths, depth, leaf, act, can_exp)
 
         leaf_states = jax.vmap(
             lambda st, i: jax.tree.map(lambda x: x[i], st))(t.states, leaves)
-        if self.value_fn is not None:
-            vals = jax.vmap(jax.vmap(self.value_fn))(leaf_states)  # [G, L]
-            val_sum = vals * p
-        elif komi is None:
-            one = lambda st, ks: jax.vmap(                         # noqa: E731
-                lambda k: self.engine.playout_value(st, k))(ks)
-            vals = jax.vmap(jax.vmap(one))(leaf_states, pkeys)     # [G, L, P]
-            val_sum = vals.sum(axis=-1)
-        else:
-            km = jnp.broadcast_to(jnp.asarray(komi, jnp.float32), (g,))
-            one = lambda st, ks, kv: jax.vmap(                     # noqa: E731
-                lambda k: self.engine.playout_value(st, k, kv))(ks)
-            vals = jax.vmap(
-                lambda ls, pk, kv: jax.vmap(
-                    lambda st, ks: one(st, ks, kv))(ls, pk)
-            )(leaf_states, pkeys, km)                              # [G, L, P]
-            val_sum = vals.sum(axis=-1)
+        with jax.named_scope("mcts_playout"):
+            if self.value_fn is not None:
+                vals = jax.vmap(jax.vmap(self.value_fn))(leaf_states)  # [G, L]
+                val_sum = vals * p
+            elif komi is None:
+                one = lambda st, ks: jax.vmap(                     # noqa: E731
+                    lambda k: self.engine.playout_value(st, k))(ks)
+                vals = jax.vmap(jax.vmap(one))(leaf_states, pkeys)  # [G, L, P]
+                val_sum = vals.sum(axis=-1)
+            else:
+                km = jnp.broadcast_to(jnp.asarray(komi, jnp.float32), (g,))
+                one = lambda st, ks, kv: jax.vmap(                 # noqa: E731
+                    lambda k: self.engine.playout_value(st, k, kv))(ks)
+                vals = jax.vmap(
+                    lambda ls, pk, kv: jax.vmap(
+                        lambda st, ks: one(st, ks, kv))(ls, pk)
+                )(leaf_states, pkeys, km)                          # [G, L, P]
+                val_sum = vals.sum(axis=-1)
 
         prior = t.prior
         if self.evaluator is not None:
@@ -581,8 +587,9 @@ class MCTS:
             val_sum = (1.0 - mix) * val_sum + mix * (net_val * p)
             prior = prior.at[gi, leaves].set(net_prior)
 
-        visit, value = mcts_backup(t.visit, t.value, paths, val_sum,
-                                   playouts=float(p))
+        with jax.named_scope("mcts_backup"):
+            visit, value = mcts_backup(t.visit, t.value, paths, val_sum,
+                                       playouts=float(p))
         return t._replace(visit=visit, value=value,
                           vloss=jnp.zeros_like(t.vloss), prior=prior)
 

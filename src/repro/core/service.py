@@ -79,6 +79,14 @@ games around the shard ring so one hot shard doesn't become the paper's
 32-thread knee.  With one shard the body degenerates to the exact
 single-device program, so ``mesh`` over one device is bit-identical to
 ``mesh=None`` (pinned in tests/test_sharded_service.py).
+
+Tracing: the host marks its boundaries with ``jax.profiler``
+``TraceAnnotation`` spans (``dispatcher.flush`` / ``pack`` / ``issue`` /
+``wait_count`` / ``read_rows`` / ``unpack``, ``scheduler.shed``), and the
+device program names its phases with ``jax.named_scope``
+(``service_admit``, ``service_ring``; the search's in core/mcts.py and
+go/board.py).  With the profiler off a span costs one flag check; the
+scopes change only HLO metadata (pinned in tests/test_tracing.py).
 """
 from __future__ import annotations
 
@@ -90,6 +98,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax, shard_map
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core.mcts import MCTS, SearchParams
@@ -691,23 +700,26 @@ class SearchService:
 
     def flush(self) -> None:
         """Push host-buffered submissions into the device queues."""
-        pushed = False
-        for pending, push, mpush in (
-                (self._pending_games, self._push_games,
-                 getattr(self, "_push_games_mesh", None)),
-                (self._pending_serve, self._push_serve,
-                 getattr(self, "_push_serve_mesh", None))):
-            while pending:
-                rows = pending[:self._chunk]
-                del pending[:self._chunk]
-                req, shards = self._pack(rows)
-                if self.mesh is None:
-                    self._pool = push(self._pool, req, jnp.int32(len(rows)))
-                else:
-                    self._pool = mpush(self._pool, req, shards)
-                pushed = True
-        if pushed:
-            self.host_syncs += 1
+        with TraceAnnotation("dispatcher.flush"):
+            pushed = False
+            for pending, push, mpush in (
+                    (self._pending_games, self._push_games,
+                     getattr(self, "_push_games_mesh", None)),
+                    (self._pending_serve, self._push_serve,
+                     getattr(self, "_push_serve_mesh", None))):
+                while pending:
+                    rows = pending[:self._chunk]
+                    del pending[:self._chunk]
+                    with TraceAnnotation("dispatcher.pack"):
+                        req, shards = self._pack(rows)
+                    if self.mesh is None:
+                        self._pool = push(self._pool, req,
+                                          jnp.int32(len(rows)))
+                    else:
+                        self._pool = mpush(self._pool, req, shards)
+                    pushed = True
+            if pushed:
+                self.host_syncs += 1
 
     def _pack(self, rows: List[_Pending]):
         pad = self._chunk - len(rows)
@@ -876,6 +888,7 @@ class SearchService:
         games = _queue_push(gq._replace(size=gq.size - d), got, got_n)
         return pool._replace(games=games)
 
+    @jax.named_scope("service_admit")
     def _admit(self, pool: PoolState) -> PoolState:
         """Device-side refill: fill empty slots from the pending queues.
 
@@ -1052,6 +1065,7 @@ class SearchService:
                              eval_sum=(pool.eval_sum + guided_a.sum()
                                        + guided_b.sum()))
 
+    @jax.named_scope("service_ring")
     def _append_ring(self, ring: _Ring, finished, sl: _Slots, actions,
                      winner, moves, nodes, visits, step) -> _Ring:
         R = ring.ticket.shape[0]
@@ -1091,8 +1105,10 @@ class SearchService:
     def dispatch(self, steps: Optional[int] = None) -> None:
         """Run ``steps`` (default ``superstep``) moves without host sync."""
         fn = self._dispatch if self.mesh is None else self._dispatch_mesh
-        work, ring = fn(self._pool._replace(ring=None), self._pool.ring,
-                        int(steps or self.superstep))
+        steps = int(steps or self.superstep)
+        with TraceAnnotation("dispatcher.issue", steps=steps):
+            work, ring = fn(self._pool._replace(ring=None), self._pool.ring,
+                            steps)
         self._pool = work._replace(ring=ring)
 
     def dispatch_async(self, steps: Optional[int] = None) -> RingView:
@@ -1137,7 +1153,8 @@ class SearchService:
                 "stale RingView: the service was reset() after this "
                 "superstep was issued")
         ring = self._pool.ring if view is None else view.ring
-        counts = np.atleast_1d(np.asarray(self._get(ring.count)))
+        with TraceAnnotation("dispatcher.wait_count"):
+            counts = np.atleast_1d(np.asarray(self._get(ring.count)))
         self.host_syncs += 1
         news = {}
         for s in range(self.n_shard):
@@ -1154,43 +1171,46 @@ class SearchService:
             return []
         bufs = tuple(getattr(ring, f) for f in self._RING_FIELDS)
         rows = {}
-        if view is None:
-            gathers = {}
-            for s in news:
-                sb = bufs if self.mesh is None \
-                    else jax.tree.map(lambda buf: buf[s], bufs)
-                idx = jnp.asarray(
-                    [i % self.ring_capacity
-                     for i in range(int(self._ring_read[s]), int(counts[s]))])
-                gathers[s] = jax.tree.map(lambda buf: buf[idx], sb)
-            rows = self._get(gathers)           # one blocking transfer
-        else:
-            whole = self._get(bufs)             # raw back-buffer read
-            for s in news:
-                sb = whole if self.mesh is None \
-                    else tuple(b[s] for b in whole)
-                idx = np.asarray(
-                    [i % self.ring_capacity
-                     for i in range(int(self._ring_read[s]), int(counts[s]))])
-                rows[s] = tuple(np.asarray(b)[idx] for b in sb)
+        with TraceAnnotation("dispatcher.read_rows",
+                             rows=sum(news.values())):
+            if view is None:
+                gathers = {}
+                for s in news:
+                    sb = bufs if self.mesh is None \
+                        else jax.tree.map(lambda buf: buf[s], bufs)
+                    idx = jnp.asarray(
+                        [i % self.ring_capacity for i in
+                         range(int(self._ring_read[s]), int(counts[s]))])
+                    gathers[s] = jax.tree.map(lambda buf: buf[idx], sb)
+                rows = self._get(gathers)       # one blocking transfer
+            else:
+                whole = self._get(bufs)         # raw back-buffer read
+                for s in news:
+                    sb = whole if self.mesh is None \
+                        else tuple(b[s] for b in whole)
+                    idx = np.asarray(
+                        [i % self.ring_capacity for i in
+                         range(int(self._ring_read[s]), int(counts[s]))])
+                    rows[s] = tuple(np.asarray(b)[idx] for b in sb)
         self.host_syncs += 1
         out: List[SearchResult] = []
-        for s in sorted(rows):
-            ticket, lane, action, winner, moves, nodes, a_black, visits, \
-                step = rows[s]
-            for j in range(news[s]):
-                rec = SearchResult(
-                    ticket=int(ticket[j]), lane=int(lane[j]),
-                    action=int(action[j]), winner=float(winner[j]),
-                    moves=int(moves[j]), tree_nodes=int(nodes[j]),
-                    a_is_black=bool(a_black[j]),
-                    root_visits=np.array(visits[j]),
-                    finished_step=int(step[j]))
-                self._completed[rec.lane] += 1
-                cls, assigned = self._assigned.pop(rec.ticket)
-                self._placement.release(cls, assigned)
-                out.append(rec)
-            self._ring_read[s] = counts[s]
+        with TraceAnnotation("dispatcher.unpack"):
+            for s in sorted(rows):
+                ticket, lane, action, winner, moves, nodes, a_black, \
+                    visits, step = rows[s]
+                for j in range(news[s]):
+                    rec = SearchResult(
+                        ticket=int(ticket[j]), lane=int(lane[j]),
+                        action=int(action[j]), winner=float(winner[j]),
+                        moves=int(moves[j]), tree_nodes=int(nodes[j]),
+                        a_is_black=bool(a_black[j]),
+                        root_visits=np.array(visits[j]),
+                        finished_step=int(step[j]))
+                    self._completed[rec.lane] += 1
+                    cls, assigned = self._assigned.pop(rec.ticket)
+                    self._placement.release(cls, assigned)
+                    out.append(rec)
+                self._ring_read[s] = counts[s]
         return out
 
     def peek_landed(self) -> bool:
@@ -1280,15 +1300,16 @@ class SearchService:
         now = time.monotonic() if now is None else now
         shed: List[int] = []
         keep: List[_Pending] = []
-        for p in self._pending_serve:
-            if p.deadline is not None and now >= p.deadline:
-                cls, shard = self._assigned.pop(p.ticket)
-                self._placement.release(cls, shard)
-                self._shed[p.lane] += 1
-                shed.append(p.ticket)
-            else:
-                keep.append(p)
-        self._pending_serve[:] = keep
+        with TraceAnnotation("scheduler.shed"):
+            for p in self._pending_serve:
+                if p.deadline is not None and now >= p.deadline:
+                    cls, shard = self._assigned.pop(p.ticket)
+                    self._placement.release(cls, shard)
+                    self._shed[p.lane] += 1
+                    shed.append(p.ticket)
+                else:
+                    keep.append(p)
+            self._pending_serve[:] = keep
         return shed
 
     @property

@@ -33,6 +33,8 @@ from __future__ import annotations
 import collections
 from typing import List, Optional
 
+from jax.profiler import TraceAnnotation
+
 
 class DispatchPipeline:
     """Keeps up to ``depth`` supersteps in flight over one SearchService.
@@ -77,16 +79,17 @@ class DispatchPipeline:
         Returns the number of supersteps issued.
         """
         svc = self.service
-        self._evict_stale()
-        svc.flush()
-        issued = 0
-        while len(self._window) < self.depth and svc.outstanding > 0:
-            self._window.append(svc.dispatch_async(self.steps))
-            self.steps_issued += self.steps
-            issued += 1
-        self.max_in_flight = max(self.max_in_flight, len(self._window))
-        if self.depth > 1 and self._window:
-            svc.peek_landed()
+        with TraceAnnotation("scheduler.pump"):
+            self._evict_stale()
+            svc.flush()
+            issued = 0
+            while len(self._window) < self.depth and svc.outstanding > 0:
+                self._window.append(svc.dispatch_async(self.steps))
+                self.steps_issued += self.steps
+                issued += 1
+            self.max_in_flight = max(self.max_in_flight, len(self._window))
+            if self.depth > 1 and self._window:
+                svc.peek_landed()
         return issued
 
     def reconcile(self, block: bool = True) -> List:
@@ -109,7 +112,8 @@ class DispatchPipeline:
         if not block and not head.ring.count.is_ready():
             return []
         self._window.popleft()
-        out = self.service.poll(view=head if self.depth > 1 else None)
+        with TraceAnnotation("scheduler.reconcile"):
+            out = self.service.poll(view=head if self.depth > 1 else None)
         self.reconciles += 1
         submitted, completed, in_flight = self.service.accounting()
         shed = self.service.shed_total

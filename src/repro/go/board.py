@@ -100,6 +100,7 @@ class GoEngine:
 
     # -- groups & liberties -----------------------------------------------------
 
+    @jax.named_scope("go_flood_fill")
     def _min_label_components(self, active: jax.Array,
                               same: jax.Array) -> jax.Array:
         """Min-index connected-component labels over the neighbour graph.
@@ -126,6 +127,7 @@ class GoEngine:
 
         return jax.lax.fori_loop(0, self.label_rounds, body, ids0)
 
+    @jax.named_scope("go_group_info")
     def group_info(self, board: jax.Array):
         """Connected components + exact per-group liberty counts.
 

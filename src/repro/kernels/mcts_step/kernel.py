@@ -249,6 +249,7 @@ def mcts_select_pallas(visit, value, vloss, prior, legal, children, expanded,
         out_specs=(pvec, lvec, lvec, lvec, lvec, vec),
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="mcts_select",
     )(*map(_rows, (visit, value, vloss)), prior, legal, children,
       *map(_rows, (expanded, terminal, player)), *map(_rows, scalars))
     return paths, depth[:, 0], leaf[:, 0], act[:, 0], can_exp[:, 0], vl[:, 0]
@@ -270,5 +271,6 @@ def mcts_backup_pallas(visit, value, paths, val_sum, *, playouts: float,
         out_specs=(vec, vec),
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="mcts_backup",
     )(paths, *map(_rows, (val_sum, visit, value)))
     return visit[:, 0], value[:, 0]

@@ -124,6 +124,7 @@ def uct_scores_pallas(child_visit, child_value, child_vloss, prior, legal,
                       col, col, col, col, col, col],
             out_specs=tile,
             interpret=interpret,
+            name="uct_scores_blend",
         )(child_visit, child_value, child_vloss, prior, legal, has_child,
           n_legal, parent_n, player, c_uct, vl_weight, prior_w)
     return pl.pallas_call(
@@ -133,5 +134,6 @@ def uct_scores_pallas(child_visit, child_value, child_vloss, prior, legal,
         in_specs=[tile, tile, tile, tile, tile, tile, col, col, col, col],
         out_specs=tile,
         interpret=interpret,
+        name="uct_scores",
     )(child_visit, child_value, child_vloss, prior, legal, has_child,
       parent_n, player, c_uct, vl_weight)

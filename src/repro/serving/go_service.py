@@ -61,12 +61,14 @@ Typical use::
 """
 from __future__ import annotations
 
+import contextlib
 import math
 import time
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.config import MCTSConfig
 from repro.core.mcts import MCTS
@@ -293,6 +295,7 @@ class GoService:
         self._shed_new: List[int] = []             # shed since last pop_shed
         self._next_ticket = 0
         self._rng = np.random.default_rng(seed)
+        self.host_busy_s = 0.0    # submit + poll time not spent blocked
         if self.unified:
             svc = self._make_service(self.default_komi)
             self._buckets[self.default_komi] = svc
@@ -344,6 +347,19 @@ class GoService:
     def host_blocked_s(self) -> float:
         """Total time spent waiting on devices across all buckets."""
         return sum(b.host_blocked_s for b in self._buckets.values())
+
+    @contextlib.contextmanager
+    def _host_span(self, name: str, **args):
+        """A profiler span ``name`` whose time, less the blocking device
+        waits inside it (``host_blocked_s`` growth), adds to
+        ``host_busy_s``."""
+        t0, blocked0 = time.perf_counter(), self.host_blocked_s
+        try:
+            with TraceAnnotation(name, **args):
+                yield
+        finally:
+            self.host_busy_s += (time.perf_counter() - t0) \
+                - (self.host_blocked_s - blocked0)
 
     @property
     def outstanding(self) -> int:
@@ -441,55 +457,61 @@ class GoService:
         result), ``shed`` raises :class:`DeadlineExceededError` (counted
         ``shed_deadline``).  With ``deadline_ms=None`` the submission is
         bit-identical to the pre-SLO path.
+
+        The time spent here, less any blocking device wait, counts into
+        ``host_busy_s``.
         """
-        komi = self.default_komi if komi is None else float(komi)
-        svc = self._bucket(komi)
-        now = time.monotonic()
-        depth = (self._sched.buckets[komi].outstanding if self.unified
-                 else svc.outstanding)
-        if depth >= self.admission_limit:
-            self.metrics.bump("shed_overload")
-            raise OverCapacityError(
-                f"bucket komi={komi} over capacity: {depth} outstanding "
-                f">= admission limit {self.admission_limit}")
-        full = int(sims) if 0 < int(sims) <= self.max_sims else self.max_sims
-        deadline = None
-        granted, downgraded = full, False
-        if deadline_ms is not None:
-            remaining = float(deadline_ms) / 1e3
-            deadline = now + remaining
-            verdict, granted = self.deadline_policy.decide(
-                remaining, depth, full)
-            if verdict == "shed":
-                self.metrics.bump("shed_deadline")
-                floor_est = self.deadline_policy.estimate_s(
-                    self.deadline_policy.floor_sims, depth)
-                raise DeadlineExceededError(
-                    f"deadline {deadline_ms:.0f}ms unmeetable at queue "
-                    f"depth {depth} (~{floor_est * 1e3:.0f}ms needed at "
-                    "the floor budget)")
-            downgraded = verdict == "downgrade"
-            if downgraded:
-                self.metrics.bump("downgraded")
-        if key is None:
-            key = self._rng.integers(0, 2 ** 32, size=(2,), dtype=np.uint32)
-        state = self._to_state(board, to_play, svc.engine)
-        if self.unified:
-            inner = self._sched.submit_serve(
-                komi, state, key=key, sims=granted, c_uct=c_uct,
-                virtual_loss=virtual_loss, prior_weight=prior_weight,
-                deadline=deadline)
-        else:
-            inner = svc.submit_serve(state, key=key, sims=granted,
-                                     c_uct=c_uct, virtual_loss=virtual_loss,
-                                     prior_weight=prior_weight,
-                                     deadline=deadline)
-        ticket = self._next_ticket
-        self._next_ticket += 1
-        self._tickets[ticket] = _Ticket(komi, inner, now, deadline,
-                                        granted, downgraded, depth)
-        self.metrics.bump("submitted")
-        return ticket
+        with self._host_span("serving.submit", ticket=self._next_ticket):
+            komi = self.default_komi if komi is None else float(komi)
+            svc = self._bucket(komi)
+            now = time.monotonic()
+            depth = (self._sched.buckets[komi].outstanding if self.unified
+                     else svc.outstanding)
+            if depth >= self.admission_limit:
+                self.metrics.bump("shed_overload")
+                raise OverCapacityError(
+                    f"bucket komi={komi} over capacity: {depth} outstanding "
+                    f">= admission limit {self.admission_limit}")
+            full = (int(sims) if 0 < int(sims) <= self.max_sims
+                    else self.max_sims)
+            deadline = None
+            granted, downgraded = full, False
+            if deadline_ms is not None:
+                remaining = float(deadline_ms) / 1e3
+                deadline = now + remaining
+                verdict, granted = self.deadline_policy.decide(
+                    remaining, depth, full)
+                if verdict == "shed":
+                    self.metrics.bump("shed_deadline")
+                    floor_est = self.deadline_policy.estimate_s(
+                        self.deadline_policy.floor_sims, depth)
+                    raise DeadlineExceededError(
+                        f"deadline {deadline_ms:.0f}ms unmeetable at queue "
+                        f"depth {depth} (~{floor_est * 1e3:.0f}ms needed at "
+                        "the floor budget)")
+                downgraded = verdict == "downgrade"
+                if downgraded:
+                    self.metrics.bump("downgraded")
+            if key is None:
+                key = self._rng.integers(0, 2 ** 32, size=(2,),
+                                         dtype=np.uint32)
+            state = self._to_state(board, to_play, svc.engine)
+            if self.unified:
+                inner = self._sched.submit_serve(
+                    komi, state, key=key, sims=granted, c_uct=c_uct,
+                    virtual_loss=virtual_loss, prior_weight=prior_weight,
+                    deadline=deadline)
+            else:
+                inner = svc.submit_serve(
+                    state, key=key, sims=granted, c_uct=c_uct,
+                    virtual_loss=virtual_loss, prior_weight=prior_weight,
+                    deadline=deadline)
+            ticket = self._next_ticket
+            self._next_ticket += 1
+            self._tickets[ticket] = _Ticket(komi, inner, now, deadline,
+                                            granted, downgraded, depth)
+            self.metrics.bump("submitted")
+            return ticket
 
     def flush(self) -> None:
         """Push every bucket's queued submissions to its device queues."""
@@ -569,38 +591,40 @@ class GoService:
         start offset each call so every bucket periodically gets the
         round's first flush.  Completed requests land their
         queue/dispatch/total latencies in :attr:`metrics` and
-        recalibrate the deadline policy.
+        recalibrate the deadline policy.  The call's time, less its
+        blocking device waits, counts into ``host_busy_s``.
         """
-        if self.unified:
-            return self._poll_unified()
-        done = []
-        inner_to_ticket = {(t.komi, t.inner): ticket
-                           for ticket, t in self._tickets.items()
-                           if ticket not in self._done
-                           and ticket not in self._shed_tickets}
-        items = list(self._buckets.items())
-        if len(items) > 1:            # pump fairness: rotate the start
-            off = self._poll_rot % len(items)
-            self._poll_rot += 1
-            items = items[off:] + items[:off]
-        for komi, svc in items:
-            if svc.outstanding == 0:
-                continue
-            now = time.monotonic()
-            for inner in svc.shed_expired(now):
-                ticket = inner_to_ticket.pop((komi, inner), None)
-                if ticket is not None:
-                    self._shed_with_calibration(ticket, now)
-            pipe = self._pipes[komi]
-            pipe.pump()
-            self._mark_flushed(time.monotonic(), komi=komi)
-            for rec in pipe.reconcile():
-                ticket = inner_to_ticket.get((komi, rec.ticket))
-                if ticket is None:
-                    continue        # a game lane sharing the bucket
-                self._record_done(ticket, rec, svc.engine)
-                done.append(ticket)
-        return done
+        with self._host_span("serving.poll"):
+            if self.unified:
+                return self._poll_unified()
+            done = []
+            inner_to_ticket = {(t.komi, t.inner): ticket
+                               for ticket, t in self._tickets.items()
+                               if ticket not in self._done
+                               and ticket not in self._shed_tickets}
+            items = list(self._buckets.items())
+            if len(items) > 1:            # pump fairness: rotate the start
+                off = self._poll_rot % len(items)
+                self._poll_rot += 1
+                items = items[off:] + items[:off]
+            for komi, svc in items:
+                if svc.outstanding == 0:
+                    continue
+                now = time.monotonic()
+                for inner in svc.shed_expired(now):
+                    ticket = inner_to_ticket.pop((komi, inner), None)
+                    if ticket is not None:
+                        self._shed_with_calibration(ticket, now)
+                pipe = self._pipes[komi]
+                pipe.pump()
+                self._mark_flushed(time.monotonic(), komi=komi)
+                for rec in pipe.reconcile():
+                    ticket = inner_to_ticket.get((komi, rec.ticket))
+                    if ticket is None:
+                        continue        # a game lane sharing the bucket
+                    self._record_done(ticket, rec, svc.engine)
+                    done.append(ticket)
+            return done
 
     def _poll_unified(self) -> List[int]:
         """One scheduler round: shed, pump once, reconcile once —

@@ -245,6 +245,7 @@ class GoMoveServer:
             "admission_limit": svc.admission_limit,
             "host_syncs": svc.host_syncs,
             "host_blocked_s": svc.host_blocked_s,
+            "host_busy_s": svc.host_busy_s,
             # per-bucket occupancy / queue depth / in-flight supersteps
             "scheduler": svc.scheduler_stats(),
             "shard_occupancy": [float(x) for x in svc.shard_occupancy()],

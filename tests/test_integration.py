@@ -127,14 +127,17 @@ print("OK train cli resume")
         assert "OK train cli resume" in out
         assert "[resume] restored step 4" in out
 
-    def test_selfplay_cli(self):
+    def test_selfplay_cli(self, tmp_path):
+        # a cache directory of its own: the checkout's default one is
+        # what tests/test_chip_smoke.py watches for stray writes
         out = run_sub("""
 import sys
 sys.argv = ["selfplay", "--board", "5", "--lanes", "1", "--sims", "8",
             "--games", "2", "--max-nodes", "64"]
 from repro.launch.selfplay import main
 main()
-""", devices=1, timeout=600)
+""", devices=1, timeout=600,
+                      env_extra={"JAX_COMPILATION_CACHE_DIR": str(tmp_path)})
         assert "win rate" in out
 
 

@@ -7,8 +7,13 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 The persistent compilation cache is off around the compiles, since an
 entry compiled for a described chip cannot be read back without one.
+
+Each kernel carries an explicit ``name``: the compiled custom call is
+named after it, whatever Python function wraps the call, so a profiler
+trace finds it under that name.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -51,9 +56,12 @@ def _shapes(sharding, *specs):
             for shape, dtype in specs]
 
 
-def _compile(fn, args):
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+def _compile(fn, args, name: str):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    calls = re.findall(r"%([\w.]+) = [^\n]*? custom-call\(", text)
+    assert calls and all(re.fullmatch(rf"{name}(\.\d+)?", c)
+                         for c in calls), calls
 
 
 @pytest.mark.parametrize("blend", [False, True])
@@ -62,7 +70,8 @@ def test_uct_scores(one_chip, rows, blend):
     f32 = jnp.float32
     tile, col = ((rows, 128), f32), ((rows, 1), f32)
     args = _shapes(one_chip, *[tile] * 6, *[col] * (6 if blend else 4))
-    _compile(lambda *a: uct_scores_pallas(*a, use_puct=False), args)
+    _compile(lambda *a: uct_scores_pallas(*a, use_puct=False), args,
+             "uct_scores_blend" if blend else "uct_scores")
 
 
 @pytest.mark.parametrize("size,blend", [(BENCH, False), (FUEGO9, False),
@@ -80,7 +89,7 @@ def test_mcts_select(one_chip, size, blend):
     # kernel raises its limit
     _compile(lambda *x: mcts_select_pallas(
         *x, lanes=lanes, max_depth=depth, expand_threshold=1,
-        use_puct=False), args)
+        use_puct=False), args, "mcts_select")
 
 
 @pytest.mark.parametrize("size", [BENCH, FUEGO9], ids=["bench", "fuego9"])
@@ -89,4 +98,5 @@ def test_mcts_backup(one_chip, size):
     f32 = jnp.float32
     args = _shapes(one_chip, ((g, n), f32), ((g, n), f32),
                    ((g, lanes, depth), jnp.int32), ((g, lanes), f32))
-    _compile(lambda *x: mcts_backup_pallas(*x, playouts=1.0), args)
+    _compile(lambda *x: mcts_backup_pallas(*x, playouts=1.0), args,
+             "mcts_backup")
