@@ -8,10 +8,11 @@ Tromp–Taylor (Chinese/area) scoring.
 Representation
 --------------
 * ``board``: ``int8[n2]`` flattened, ``+1`` black / ``-1`` white / ``0`` empty.
-* All neighbour/diagonal lookups go through precomputed tables padded with a
+* Neighbour/diagonal lookups go through precomputed tables padded with a
   sentinel index ``n2`` that maps to an off-board "wall" cell, so gathers never
   need bounds checks (the wall never matches any colour test that matters and
-  scatters to it are discarded).
+  scatters to it are discarded).  The flood fill's rounds read neighbours by
+  static shifts of the flat board instead (see ``_min_label_components``).
 * Moves are ``0..n2-1`` for points and ``n2`` for pass.
 
 The engine object holds only *static* numpy tables; every method is a pure
@@ -74,11 +75,13 @@ class GoEngine:
         # number of on-board neighbours/diagonals per point
         self.nbr_valid = jnp.asarray((nbr < self.n2), dtype=jnp.int32)
         self.diag_valid = jnp.asarray((diag < self.n2), dtype=jnp.int32)
-        # Static trip count for the min-label component fixpoint.  Hook +
-        # one pointer-jump converges in O(log n2) rounds; stress-tested over
-        # random boards plus adversarial serpentine/spiral/comb families
-        # (worst observed: 10/16/19/27 rounds at sizes 5/9/13/19 vs bounds
-        # 21/27/30/33 from this formula).
+        # Static trip count for the min-label component fixpoint.  A round
+        # hooks each cell to its neighbour min (four static shifts) and
+        # then pointer-jumps once (a one-hot compare); that converges in
+        # O(log n2) rounds.  Stress-tested over random boards plus
+        # adversarial serpentine/spiral/comb families (worst observed:
+        # 10/16/19/27 rounds at sizes 5/9/13/19 vs bounds 21/27/30/33 from
+        # this formula).
         self.label_rounds = 3 * max(1, (self.n2 - 1).bit_length()) + 6
 
     # -- state ----------------------------------------------------------------
@@ -106,23 +109,38 @@ class GoEngine:
         """Min-index connected-component labels over the neighbour graph.
 
         ``active`` is ``bool[n2]`` (cells that participate); ``same`` is
-        ``bool[n2, 4]`` (which neighbour edges connect).  Returns
-        ``int32[n2]`` labels: the smallest cell index in each component,
-        ``n2`` for inactive cells — the same fixpoint the old data-dependent
-        ``while_loop`` reached, but via a *static* ``fori_loop`` trip count
-        (hook to the neighbour min, then one pointer jump per round,
-        FastSV-style) so the loop is shaped for a Pallas port: fixed rounds,
-        fixed-size gathers, no convergence flag.
+        ``bool[n2, 4]`` (which neighbour edges connect, ordered as
+        ``nbr``).  Returns ``int32[n2]`` labels: the smallest cell index in
+        each component, ``n2`` for inactive cells.  Each of the static
+        ``label_rounds`` hooks every cell to its neighbour min, then
+        pointer-jumps once (FastSV-style), with no gather in the round:
+
+        * the hook reads each neighbour's label by a static shift of the
+          flat board, masked to the edges that are on the board and
+          connect (an off-board edge reads ``n2``, the wall);
+        * the jump ``new[new]`` is a one-hot compare: for each target
+          ``j``, the cells whose ``new`` is ``j`` take ``new[j]``, reduced
+          by a min over ``j`` (``n2`` matches no target and stays).
+
+        Exact integer work: every round gives the labels that indexing
+        ``new`` and the ``nbr`` table would.
         """
         n2 = self.n2
-        ids0 = jnp.where(active, jnp.arange(n2, dtype=jnp.int32), n2)
+        cells = jnp.arange(n2, dtype=jnp.int32)
+        ids0 = jnp.where(active, cells, n2)
+        links = [same[:, k] & (self.nbr_valid[:, k] == 1) for k in range(4)]
+        # ``roll(x, s)[p] == x[nbr[p, k]]`` wherever ``nbr[p, k]`` is on the
+        # board, for nbr's up/down/left/right
+        shifts = (self.size, -self.size, 1, -1)
 
         def body(_, ids):
-            idp = self._pad(ids, n2)
-            cand = jnp.where(same, idp[self.nbr], n2)     # hook: nbr min
-            new = jnp.minimum(ids, cand.min(axis=1))
-            newp = self._pad(new, n2)
-            new = jnp.minimum(new, newp[new])             # pointer jump
+            new = ids
+            for link, shift in zip(links, shifts):        # hook
+                new = jnp.minimum(new, jnp.where(link, jnp.roll(ids, shift),
+                                                 n2))
+            hit = new[None, :] == cells[:, None]          # [target j, n2]
+            jump = jnp.where(hit, new[:, None], n2).min(axis=0)
+            new = jnp.minimum(new, jump)                  # pointer jump
             return jnp.where(active, new, n2)
 
         return jax.lax.fori_loop(0, self.label_rounds, body, ids0)
