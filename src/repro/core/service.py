@@ -84,9 +84,12 @@ Tracing: the host marks its boundaries with ``jax.profiler``
 ``TraceAnnotation`` spans (``dispatcher.flush`` / ``pack`` / ``issue`` /
 ``wait_count`` / ``read_rows`` / ``unpack``, ``scheduler.shed``), and the
 device program names its phases with ``jax.named_scope``
-(``service_admit``, ``service_ring``; the search's in core/mcts.py and
-go/board.py).  With the profiler off a span costs one flag check; the
-scopes change only HLO metadata (pinned in tests/test_tracing.py).
+(``service_admit``, ``service_ring``; under a mesh ``service_rebalance``
+and ``service_push``; the search's in core/mcts.py and go/board.py).
+With the profiler off a span costs one flag check; the scopes change
+only HLO metadata (pinned in tests/test_tracing.py).  ``read_rows_s``
+adds up the wall time of every ``dispatcher.read_rows`` span, its
+blocking transfer included: the host's per-shard ring reads.
 """
 from __future__ import annotations
 
@@ -501,6 +504,7 @@ class SearchService:
         self._shed = dict(self._submitted)
         self.host_syncs = 0           # host<->device round-trips (flush+poll)
         self.host_blocked_s = 0.0     # time spent waiting on the device
+        self.read_rows_s = 0.0        # time in poll's ring-row reads
         self.last_drain_stats = {}    # DispatchPipeline.stats() of last drain
         self._epoch += 1              # invalidates outstanding RingViews
 
@@ -809,6 +813,7 @@ class SearchService:
         """
         spec = PartitionSpec(self._axis)
 
+        @jax.named_scope("service_push")
         def body(p, req, shards):
             local = jax.tree.map(lambda x: x[0], p)
             me = lax.axis_index(self._axis)
@@ -825,6 +830,7 @@ class SearchService:
             in_specs=(spec, PartitionSpec(), PartitionSpec()),
             out_specs=spec, check_vma=False)(pool, req, shards)
 
+    @jax.named_scope("service_rebalance")
     def _rebalance_impl(self, pool: PoolState) -> PoolState:
         """Rebalance surplus pending games along the shard ring.
 
@@ -1171,8 +1177,9 @@ class SearchService:
             return []
         bufs = tuple(getattr(ring, f) for f in self._RING_FIELDS)
         rows = {}
+        t0 = time.perf_counter()
         with TraceAnnotation("dispatcher.read_rows",
-                             rows=sum(news.values())):
+                             rows=sum(news.values()), shards=len(news)):
             if view is None:
                 gathers = {}
                 for s in news:
@@ -1192,6 +1199,7 @@ class SearchService:
                         [i % self.ring_capacity for i in
                          range(int(self._ring_read[s]), int(counts[s]))])
                     rows[s] = tuple(np.asarray(b)[idx] for b in sb)
+        self.read_rows_s += time.perf_counter() - t0
         self.host_syncs += 1
         out: List[SearchResult] = []
         with TraceAnnotation("dispatcher.unpack"):

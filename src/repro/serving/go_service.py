@@ -348,6 +348,11 @@ class GoService:
         """Total time spent waiting on devices across all buckets."""
         return sum(b.host_blocked_s for b in self._buckets.values())
 
+    @property
+    def read_rows_s(self) -> float:
+        """Total time spent reading result-ring rows across all buckets."""
+        return sum(b.read_rows_s for b in self._buckets.values())
+
     @contextlib.contextmanager
     def _host_span(self, name: str, **args):
         """A profiler span ``name`` whose time, less the blocking device

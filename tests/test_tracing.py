@@ -7,13 +7,24 @@
   of them by name.
 * ``GoService.host_busy_s``: the time spent in ``submit`` and ``poll``
   less the blocking device waits in them.
-* Device scopes (``jax.named_scope``) on the search phases change only
-  HLO metadata: the dispatch program compiled with them equals the one
-  compiled with them switched off, instruction for instruction, once
-  metadata is stripped and the instructions are renamed in order.
+* ``SearchService.read_rows_s``: the time in ``poll``'s ring-row reads,
+  on polls that read rows only; ``GoService.read_rows_s`` sums it over
+  the komi buckets.
+* Device scopes (``jax.named_scope``) on the search phases, and on the
+  mesh-only rebalance and push, change only HLO metadata: the program
+  compiled with them equals the one compiled with them switched off,
+  instruction for instruction, once metadata is stripped and the
+  instructions are renamed in order.  The mesh programs are compiled in
+  a subprocess with four CPU devices.
 """
+import contextlib
+import json
+import os
 import re
+import subprocess
+import sys
 import time
+import types
 
 import jax
 import numpy as np
@@ -27,6 +38,7 @@ from repro.serving.go_service import GoService
 
 SCOPES = ("mcts_select", "mcts_expand", "mcts_playout", "mcts_backup",
           "go_flood_fill", "go_group_info", "service_admit", "service_ring")
+MESH_SCOPES = ("service_rebalance", "service_push")
 DISPATCHER = ("dispatcher.flush", "dispatcher.pack", "dispatcher.issue",
               "dispatcher.wait_count", "dispatcher.read_rows",
               "dispatcher.unpack")
@@ -80,6 +92,7 @@ def test_drain_writes_dispatcher_and_scheduler_spans(service, tmp_path):
     assert {s.get("steps") for s in spans["dispatcher.issue"]} \
         == {service.superstep}
     assert sum(s["rows"] for s in spans["dispatcher.read_rows"]) == 3
+    assert {s["shards"] for s in spans["dispatcher.read_rows"]} == {1}
 
 
 def test_go_service_writes_serving_spans(go_service, tmp_path):
@@ -133,6 +146,52 @@ def test_host_busy_s_excludes_blocked_time(go_service, monkeypatch):
     assert busy + blocked == pytest.approx(inside, abs=0.02)
 
 
+def test_read_rows_s_counts_polls_that_read_rows(service, engine5):
+    service.reset(seed=2, ring_capacity=8)
+    assert service.read_rows_s == 0.0
+    service.dispatch()
+    assert service.poll() == [] and service.read_rows_s == 0.0
+    service.submit_serve(engine5.init_state(),
+                         key=np.asarray([0, 5], np.uint32))
+    service.flush()
+    service.dispatch()
+    assert len(service.poll()) == 1
+    read = service.read_rows_s
+    assert read > 0.0
+    service.dispatch()
+    assert service.poll() == [] and service.read_rows_s == read
+
+
+def test_go_service_read_rows_s_sums_buckets(go_service, monkeypatch):
+    go_service.result(go_service.submit(np.zeros(25, np.int8)))
+    (inner,) = go_service._buckets.values()
+    assert go_service.read_rows_s == inner.read_rows_s > 0.0
+    # per-komi pools (``unified=False``) are summed like host_blocked_s
+    monkeypatch.setitem(go_service._buckets, 6.5,
+                        types.SimpleNamespace(read_rows_s=0.25))
+    assert go_service.read_rows_s == inner.read_rows_s + 0.25
+
+
+@contextlib.contextmanager
+def _scopes_off():
+    """Trace with the program's own scopes switched off: entering one of
+    them leaves the name stack as it was."""
+    cls = source_info_util.ExtendNameStackContextManager
+    enter = cls.__enter__
+
+    def skip_ours(self):
+        if self.name in SCOPES + MESH_SCOPES:
+            self.prev = source_info_util._source_info_context.context
+            return self.prev.name_stack
+        return enter(self)
+
+    cls.__enter__ = skip_ours
+    try:
+        yield
+    finally:
+        cls.__enter__ = enter
+
+
 def _canonical(text: str) -> str:
     """HLO text without ``metadata={...}``, without the source-location
     tables (file, function and frame names) between the module's header
@@ -150,39 +209,86 @@ def _canonical(text: str) -> str:
     return head + "\n" + body
 
 
-def _dispatch_hlo(engine, fused: bool, scoped: bool, monkeypatch) -> str:
+def _scopes(text: str) -> set:
+    """The program's scopes named in an HLO text's op metadata."""
+    return {c for n in re.findall(r'op_name="([^"]*)"', text)
+            for c in n.split("/")} & set(SCOPES + MESH_SCOPES)
+
+
+def _dispatch_hlo(engine, fused: bool, scoped: bool) -> str:
     """Optimized HLO of a fresh 2-slot service's dispatch, with the
     search-phase scopes on or switched off."""
-    if not scoped:
-        enter = source_info_util.ExtendNameStackContextManager.__enter__
-
-        def skip_ours(self):
-            if self.name in SCOPES:
-                self.prev = source_info_util._source_info_context.context
-                return self.prev.name_stack
-            return enter(self)
-
-        monkeypatch.setattr(source_info_util.ExtendNameStackContextManager,
-                            "__enter__", skip_ours)
-    player = MCTS(engine, CFG, fused=fused)
-    svc = SearchService(engine, player, player, slots=2, max_moves=6)
-    svc.reset(seed=0, game_capacity=2, ring_capacity=4)
-    pool = svc._pool
-    text = svc._dispatch.lower(pool._replace(ring=None), pool.ring,
-                               svc.superstep).compile().as_text()
-    monkeypatch.undo()
-    return text
+    with contextlib.nullcontext() if scoped else _scopes_off():
+        player = MCTS(engine, CFG, fused=fused)
+        svc = SearchService(engine, player, player, slots=2, max_moves=6)
+        svc.reset(seed=0, game_capacity=2, ring_capacity=4)
+        pool = svc._pool
+        return svc._dispatch.lower(pool._replace(ring=None), pool.ring,
+                                   svc.superstep).compile().as_text()
 
 
-@pytest.mark.parametrize("fused", [False, True], ids=["unfused", "fused"])
-def test_scopes_change_only_metadata(engine5, fused, monkeypatch):
-    scoped = _dispatch_hlo(engine5, fused, True, monkeypatch)
-    plain = _dispatch_hlo(engine5, fused, False, monkeypatch)
-    def scopes(text):
-        return {c for n in re.findall(r'op_name="([^"]*)"', text)
-                for c in n.split("/")} & set(SCOPES)
+def mesh_programs_hlo(scoped: bool) -> dict:
+    """Optimized HLO of a fresh 4-shard service's mesh dispatch and mesh
+    push, with the program's scopes on or switched off.  Needs four
+    devices."""
+    from repro.compat import make_service_mesh
+    from repro.go import GoEngine
+    with contextlib.nullcontext() if scoped else _scopes_off():
+        engine = GoEngine(5, komi=0.5)
+        player = MCTS(engine, CFG)
+        svc = SearchService(engine, player, player, slots=8, max_moves=6,
+                            mesh=make_service_mesh(4))
+        svc.reset(seed=0, game_capacity=2, ring_capacity=4)
+        pool = svc._pool
+        svc.submit_serve(engine.init_state(), key=np.asarray([0, 1]))
+        req, shards = svc._pack(svc._pending_serve)
+        return {
+            "dispatch": svc._dispatch_mesh.lower(
+                pool._replace(ring=None), pool.ring,
+                svc.superstep).compile().as_text(),
+            "push": svc._push_serve_mesh.lower(
+                pool, req, shards).compile().as_text()}
 
-    assert scopes(scoped) == set(SCOPES)
-    assert scopes(plain) == set()
+
+def _mesh_hlo_in_subprocess() -> dict:
+    """``{scoped: mesh_programs_hlo(scoped)}`` from a subprocess that has
+    four CPU devices, compared there: each program's scopes and whether
+    its canonical HLO equals the other's."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(here), "src"), here,
+         env.get("PYTHONPATH", "")])
+    code = """if True:
+        import json
+        import test_tracing as t
+        on, off = t.mesh_programs_hlo(True), t.mesh_programs_hlo(False)
+        print(json.dumps({k: {"scoped": sorted(t._scopes(on[k])),
+                              "plain": sorted(t._scopes(off[k])),
+                              "equal": t._canonical(on[k])
+                              == t._canonical(off[k])} for k in on}))
+    """
+    out = subprocess.run([sys.executable, "-c", code], env=env, cwd=here,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("program", ["unfused", "fused", "mesh"])
+def test_scopes_change_only_metadata(engine5, program):
+    if program == "mesh":
+        got = _mesh_hlo_in_subprocess()
+        assert set(got["dispatch"]["scoped"]) == set(SCOPES) | {
+            "service_rebalance"}
+        assert got["push"]["scoped"] == ["service_push"]
+        for name, prog in got.items():
+            assert prog["plain"] == [], name
+            assert prog["equal"], name
+        return
+    scoped = _dispatch_hlo(engine5, program == "fused", True)
+    plain = _dispatch_hlo(engine5, program == "fused", False)
+    assert _scopes(scoped) == set(SCOPES)
+    assert _scopes(plain) == set()
     assert _canonical(scoped) == _canonical(plain)
 
